@@ -1,0 +1,8 @@
+"""Percent of the traced job's wall in which no operation ran on the
+device, averaged over the devices."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace.window_s <= 0 or not ctx.trace.busy_ns:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s() / ctx.trace.window_s)

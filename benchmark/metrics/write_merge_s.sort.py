@@ -1,0 +1,5 @@
+"""Host seconds of the ``sort_bam.write_merge`` span, per job."""
+
+
+def read(ctx):
+    return ctx.span_per_job("sort_bam.write_merge")
