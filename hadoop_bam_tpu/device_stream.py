@@ -179,11 +179,7 @@ def donating_concat(parts: Sequence):
     logically).  Ledger bookkeeping stays the caller's (``adopt``)."""
     from .utils.backend import donation_supported
 
-    donate = donation_supported()
-    out = _concat_fn(len(parts), donate)(*parts)
-    if donate:
-        METRICS.count("device_stream.concat_donations", 1)
-    return out
+    return _concat_fn(len(parts), donation_supported())(*parts)
 
 
 class DeviceStream:
@@ -350,7 +346,6 @@ class DeviceStream:
             return None
         from .ops.pallas.bcf_chain import walk_chain
 
-        self._count("bcf_walks")
         return walk_chain(payload, start, limit)
 
     def deflate_stream(
@@ -413,8 +408,6 @@ class DeviceStream:
         the double-buffer ordering drills pin this)."""
         d = max(1, int(depth)) if depth is not None else self.depth
         METRICS.set_gauge("pipeline.read_depth", d)
-        if self.armed:
-            self._count("splits", len(splits))
 
         def read_one(si, s):
             # trace_ctx tags every stage event this split's read/inflate/
@@ -535,8 +528,9 @@ class DeviceStream:
             if not keep_residency:
                 from .utils.backend import donation_supported
 
-                donate = donation_supported()
-                padded = _slice_pad_fn(n_bytes, pad_len, donate)(dd, s0)
+                padded = _slice_pad_fn(
+                    n_bytes, pad_len, donation_supported()
+                )(dd, s0)
                 # Ledger: the parse stream succeeds the window (donor
                 # closed, successor registered); its own residency ends
                 # when the chain kernel's outputs are all that remain.
@@ -548,8 +542,6 @@ class DeviceStream:
                     nbytes=pad_len,
                 )
                 b.device_data = None
-                if donate:
-                    self._count("parse_donations")
             else:
                 padded = jnp.pad(dd[s0:s1], (0, pad_len - n_bytes))
             METRICS.count("sort_bam.device_parse_residency", 1)
@@ -641,9 +633,10 @@ class DeviceStream:
         try:
             from .ops.pallas.gather_stream import gather_stream_device
 
-            gathered, _ = gather_stream_device(
-                stream_dev, src, lens, dup_mask=dm
-            )
+            with span("bam.write.gather"):
+                gathered, _ = gather_stream_device(
+                    stream_dev, src, lens, dup_mask=dm
+                )
             # The permuted gather column is a second resident stream for
             # the duration of the deflate — ledgered so the HBM track
             # shows the write-phase bump and a dropped release would be
@@ -672,8 +665,5 @@ class DeviceStream:
         finally:
             if gathered is not None:
                 LEDGER.release(gathered)
-        if dm is not None:
-            METRICS.count("bam.duplicate_flags_patched", int(dm.sum()))
         METRICS.count("bam.device_write_parts", 1)
-        self._count("parts_encoded")
         return blob

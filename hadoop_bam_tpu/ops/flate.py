@@ -120,7 +120,7 @@ import jax.numpy as jnp
 
 from .. import faults
 from ..spec import bgzf
-from ..utils.tracing import stage as _trace_stage
+from ..utils.tracing import span, stage as _trace_stage
 
 # --------------------------------------------------------------------------
 # Fixed-Huffman tables (RFC 1951 §3.2.5-3.2.6), precomputed as numpy consts.
@@ -1198,33 +1198,34 @@ def _lanes_decode_members(
     from ..utils.tracing import METRICS
     from .pallas.inflate_lanes import inflate_lanes_ex
 
-    clens_all = np.asarray(
-        [cs[i] - 20 - xlen[i] for i in idx], dtype=np.int32
-    )
-    isz_all = np.asarray([us[i] for i in idx], dtype=np.int32)
-    take: List[int] = []
-    for k in range(len(idx)):
-        ok_k, reason = inflate_lanes_accepts(
-            int(clens_all[k]), int(isz_all[k])
+    with span("flate.inflate.pack"):
+        clens_all = np.asarray(
+            [cs[i] - 20 - xlen[i] for i in idx], dtype=np.int32
         )
-        if ok_k:
-            take.append(k)
-        elif stats is not None:
-            if reason == "size":
-                stats.tierdown_size += 1
-            else:
-                stats.tierdown_vmem += 1
-    if not take:
-        if len(idx):
-            METRICS.count("flate.lanes_tierdown", len(idx))
-        return {}, len(idx), None
-    clens = clens_all[take]
-    isz = isz_all[take]
-    comp = np.zeros((len(take), max(int(clens.max()), 1)), dtype=np.uint8)
-    for k2, k in enumerate(take):
-        i = idx[k]
-        s = int(co[i]) + 12 + int(xlen[i])
-        comp[k2, : clens[k2]] = raw[s : s + clens[k2]]
+        isz_all = np.asarray([us[i] for i in idx], dtype=np.int32)
+        take: List[int] = []
+        for k in range(len(idx)):
+            ok_k, reason = inflate_lanes_accepts(
+                int(clens_all[k]), int(isz_all[k])
+            )
+            if ok_k:
+                take.append(k)
+            elif stats is not None:
+                if reason == "size":
+                    stats.tierdown_size += 1
+                else:
+                    stats.tierdown_vmem += 1
+        if not take:
+            if len(idx):
+                METRICS.count("flate.lanes_tierdown", len(idx))
+            return {}, len(idx), None
+        clens = clens_all[take]
+        isz = isz_all[take]
+        comp = np.zeros((len(take), max(int(clens.max()), 1)), np.uint8)
+        for k2, k in enumerate(take):
+            i = idx[k]
+            s = int(co[i]) + 12 + int(xlen[i])
+            comp[k2, : clens[k2]] = raw[s : s + clens[k2]]
     from ..utils.tracing import count_d2h, count_h2d
 
     count_h2d(comp.nbytes, "inflate_comp")
@@ -1255,11 +1256,12 @@ def _lanes_decode_members(
         if stats is not None:
             stats.tierdown_ok0 += len(idx)
         return {}, len(idx), None
-    decoded = {
-        idx[take[k2]]: out_l[k2, : isz[k2]].tobytes()
-        for k2 in range(len(take))
-        if ok_l[k2]
-    }
+    with span("flate.inflate.verify"):
+        decoded = {
+            idx[take[k2]]: out_l[k2, : isz[k2]].tobytes()
+            for k2 in range(len(take))
+            if ok_l[k2]
+        }
     count_d2h(int(sum(len(v) for v in decoded.values())), "inflate_out")
     if stats is not None:
         stats.tierdown_ok0 += int((~ok_l).sum())
@@ -1338,34 +1340,36 @@ def inflate_blocks_device(
         else ({}, 0, None)
     )
     fallback: List[int] = []
-    for i in live:
-        payload = decoded.get(i)
-        if payload is None:
-            fallback.append(i)
-            continue
-        if check_crc:
-            crc = struct.unpack_from(
-                "<I", raw, int(coffsets[i]) + int(csizes[i]) - 8
-            )[0]
-            if (zlib.crc32(payload) & 0xFFFFFFFF) != crc:
-                fallback.append(i)  # host re-decode decides corrupt-vs-bug
+    with span("flate.inflate.verify"):
+        for i in live:
+            payload = decoded.get(i)
+            if payload is None:
+                fallback.append(i)
                 continue
-        out[out_offsets[i] : out_offsets[i + 1]] = np.frombuffer(
-            payload, dtype=np.uint8
-        )
-    if fallback:
-        dev2d = None  # host bytes diverge from the device copy
-        f_out, f_offs = native.inflate_blocks(
-            raw,
-            co64[fallback],
-            np.asarray(csizes, dtype=np.int32)[fallback],
-            np.asarray(usizes, dtype=np.int32)[fallback],
-            check_crc=check_crc,
-        )
-        for k, i in enumerate(fallback):
-            out[out_offsets[i] : out_offsets[i + 1]] = f_out[
-                f_offs[k] : f_offs[k + 1]
-            ]
+            if check_crc:
+                crc = struct.unpack_from(
+                    "<I", raw, int(coffsets[i]) + int(csizes[i]) - 8
+                )[0]
+                if (zlib.crc32(payload) & 0xFFFFFFFF) != crc:
+                    # The host re-decode decides corrupt-vs-bug.
+                    fallback.append(i)
+                    continue
+            out[out_offsets[i] : out_offsets[i + 1]] = np.frombuffer(
+                payload, dtype=np.uint8
+            )
+        if fallback:
+            dev2d = None  # host bytes diverge from the device copy
+            f_out, f_offs = native.inflate_blocks(
+                raw,
+                co64[fallback],
+                np.asarray(csizes, dtype=np.int32)[fallback],
+                np.asarray(usizes, dtype=np.int32)[fallback],
+                check_crc=check_crc,
+            )
+            for k, i in enumerate(fallback):
+                out[out_offsets[i] : out_offsets[i + 1]] = f_out[
+                    f_offs[k] : f_offs[k + 1]
+                ]
     if not return_device:
         return out, out_offsets
     dev_flat = None
@@ -1376,9 +1380,10 @@ def inflate_blocks_device(
         from ..utils.hbm import LEDGER
         from ..utils.tracing import METRICS
 
-        dev_flat = _device_flatten(
-            dev2d, jnp.asarray(starts), int(out_offsets[-1])
-        )
+        with span("flate.inflate.flatten"):
+            dev_flat = _device_flatten(
+                dev2d, jnp.asarray(starts), int(out_offsets[-1])
+            )
         # Residency ledger: the inflate tier now owns a split window in
         # HBM; the read path transfers ownership when it attaches the
         # window to a RecordBatch, and whoever holds it last must
@@ -1643,12 +1648,13 @@ def bgzf_compress_device(
         from ..utils.hbm import LEDGER
         from .pallas.crc32 import crc32_device
 
-        crc_dev = crc32_device(
-            device_input,
-            np.arange(nblk, dtype=np.int64) * block_payload,
-            lens.astype(np.int64),
-            donate=donate_input,
-        )
+        with span("flate.deflate.crc"):
+            crc_dev = crc32_device(
+                device_input,
+                np.arange(nblk, dtype=np.int64) * block_payload,
+                lens.astype(np.int64),
+                donate=donate_input,
+            )
         if donate_input:
             from ..utils.backend import donation_supported
 
@@ -1660,42 +1666,44 @@ def bgzf_compress_device(
         LEDGER.register(
             crc_dev, kind="crc_column", holder="flate.deflate_crc"
         )
-        dev_crcs = np.asarray(crc_dev)
+        with span("flate.deflate.wait"):
+            dev_crcs = np.asarray(crc_dev)
         LEDGER.release(crc_dev)
         count_d2h(dev_crcs.nbytes, "write_crc")
-    total = int((18 + 8) * nblk + clens.sum())
-    if append_terminator:
-        total += len(bgzf.TERMINATOR)
-    buf = bytearray(total)
-    pos = 0
-    off_in = 0
-    for i in range(nblk):
-        c = int(clens[i])
-        ln = int(lens[i])
-        bsize = c + 12 + 6 + 8
-        buf[pos : pos + 4] = bgzf.MAGIC
-        struct.pack_into(
-            "<IBBHBBHH", buf, pos + 4, 0, 0, 0xFF, 6, 0x42, 0x43, 2,
-            bsize - 1,
-        )
-        pos += 18
-        od = overrides.get(i)
-        if od is not None:
-            buf[pos : pos + c] = od
-        else:
-            buf[pos : pos + c] = memoryview(comp[i, :c])
-        pos += c
-        crc = (
-            int(dev_crcs[i])
-            if dev_crcs is not None
-            else zlib.crc32(a[off_in : off_in + ln]) & 0xFFFFFFFF
-        )
-        struct.pack_into("<II", buf, pos, crc, ln)
-        pos += 8
-        off_in += ln
-    if append_terminator:
-        buf[pos:] = bgzf.TERMINATOR
-    return bytes(buf)
+    with span("flate.deflate.frame"):
+        total = int((18 + 8) * nblk + clens.sum())
+        if append_terminator:
+            total += len(bgzf.TERMINATOR)
+        buf = bytearray(total)
+        pos = 0
+        off_in = 0
+        for i in range(nblk):
+            c = int(clens[i])
+            ln = int(lens[i])
+            bsize = c + 12 + 6 + 8
+            buf[pos : pos + 4] = bgzf.MAGIC
+            struct.pack_into(
+                "<IBBHBBHH", buf, pos + 4, 0, 0, 0xFF, 6, 0x42, 0x43, 2,
+                bsize - 1,
+            )
+            pos += 18
+            od = overrides.get(i)
+            if od is not None:
+                buf[pos : pos + c] = od
+            else:
+                buf[pos : pos + c] = memoryview(comp[i, :c])
+            pos += c
+            crc = (
+                int(dev_crcs[i])
+                if dev_crcs is not None
+                else zlib.crc32(a[off_in : off_in + ln]) & 0xFFFFFFFF
+            )
+            struct.pack_into("<II", buf, pos, crc, ln)
+            pos += 8
+            off_in += ln
+        if append_terminator:
+            buf[pos:] = bgzf.TERMINATOR
+        return bytes(buf)
 
 
 @_trace_stage("flate.stage.deflate_device")

@@ -72,6 +72,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...utils.backend import pallas_interpret
+from ...utils.tracing import span
 
 from ..flate import DIST_BASE, DIST_EXTRA, LEN_BASE, LEN_EXTRA
 
@@ -340,8 +341,9 @@ def _kernel_factory(
     static_argnames=("w", "h", "n_chunks", "tok_tile", "chunk", "t_step",
                      "interpret"),
 )
-def _launch(streams, plens, w: int, h: int, n_chunks: int, tok_tile: int,
-            chunk: int, t_step: int, interpret: bool):
+def _deflate_match_kernel(streams, plens, w: int, h: int, n_chunks: int,
+                          tok_tile: int, chunk: int, t_step: int,
+                          interpret: bool):
     kernel = _kernel_factory(w, h, tok_tile, chunk, t_step)
     return pl.pallas_call(
         kernel,
@@ -549,37 +551,45 @@ def _encode_group(
     for one ≤128-lane group whose words are already in the transposed
     layout (host- or device-built).  Returns (comp [n, out_bytes] uint8,
     clens int32 [n], ok bool [n]) as host arrays — only the compressed
-    rows come back d2h."""
+    rows come back d2h.  Launches sit in ``flate.deflate.match`` /
+    ``.emit`` spans and each block of fetches in ``flate.deflate.wait``,
+    so a device trace charges kernel runs and host waits apart."""
     from ...utils.tracing import count_d2h
     from ..flate import _MAX_LAUNCH_ELEMS
 
     plens = np.zeros((1, LANES), dtype=np.int32)
     plens[0, :n] = plens_np
-    toks, cnts, ntok, okk = _launch(
-        words_dev, jnp.asarray(plens), g["w"], g["h"], g["n_chunks"],
-        g["tok_tile"], g["chunk"], g["t_step"], bool(interpret),
-    )
-    ntok_np = np.asarray(ntok)[0]
+    with span("flate.deflate.match"):
+        toks, cnts, ntok, okk = _deflate_match_kernel(
+            words_dev, jnp.asarray(plens), g["w"], g["h"], g["n_chunks"],
+            g["tok_tile"], g["chunk"], g["t_step"], bool(interpret),
+        )
+    with span("flate.deflate.wait"):
+        ntok_np = np.asarray(ntok)[0]
     T = _pow2_at_least(int(ntok_np.max()) + 1, 256)
-    tok_bt = _compact_tokens(toks, cnts[::_CNT_ROWS], g["tok_tile"], T)
+    with span("flate.deflate.emit"):
+        tok_bt = _compact_tokens(toks, cnts[::_CNT_ROWS], g["tok_tile"], T)
+        ntok_vec = ntok[0]
     # Two scatter updates per token: keep each launch under the cap.
     emit_step = max(1, _MAX_LAUNCH_ELEMS // (2 * T))
-    ntok_vec = ntok[0]
     comp = np.zeros((n, out_bytes), dtype=np.uint8)
     clens = np.zeros(n, dtype=np.int32)
     for r0 in range(0, n, emit_step):
         r1 = min(n, r0 + emit_step)
-        c, cl = _emit_tokens_fixed(
-            tok_bt[r0:r1], ntok_vec[r0:r1], out_bytes
-        )
-        # A TPU may hand the words back in a strided host layout; the
-        # byte view needs contiguous rows.
-        comp[r0:r1] = np.ascontiguousarray(np.asarray(c)).view(np.uint8)[
-            :, :out_bytes
-        ]
-        clens[r0:r1] = np.asarray(cl)
+        with span("flate.deflate.emit"):
+            c, cl = _emit_tokens_fixed(
+                tok_bt[r0:r1], ntok_vec[r0:r1], out_bytes
+            )
+        with span("flate.deflate.wait"):
+            # A TPU may hand the words back in a strided host layout; the
+            # byte view needs contiguous rows.
+            comp[r0:r1] = np.ascontiguousarray(np.asarray(c)).view(
+                np.uint8
+            )[:, :out_bytes]
+            clens[r0:r1] = np.asarray(cl)
     count_d2h(comp.nbytes, "deflate_comp")
-    ok = np.asarray(okk)[0, :n].astype(bool)
+    with span("flate.deflate.wait"):
+        ok = np.asarray(okk)[0, :n].astype(bool)
     return comp, clens, ok
 
 
@@ -625,19 +635,20 @@ def deflate_lanes(
     for g0 in range(0, B, LANES):
         g1 = min(B, g0 + LANES)
         n = g1 - g0
-        # Transpose the group: member j's words go down lane j.
-        grp = np.zeros((g["w"] * 4, LANES), dtype=np.uint8)
-        grp[: payload.shape[1], :n] = payload[g0:g1].T
-        words = (
-            grp.reshape(g["w"], 4, LANES).astype(np.uint32)
-            * (np.uint32(1) << (8 * np.arange(4, dtype=np.uint32)))[
-                None, :, None
-            ]
-        ).sum(axis=1).astype(np.uint32).view(np.int32)
-        count_h2d(words.nbytes, "deflate_payload")
+        with span("flate.deflate.match"):
+            # Transpose the group: member j's words go down lane j.
+            grp = np.zeros((g["w"] * 4, LANES), dtype=np.uint8)
+            grp[: payload.shape[1], :n] = payload[g0:g1].T
+            words = (
+                grp.reshape(g["w"], 4, LANES).astype(np.uint32)
+                * (np.uint32(1) << (8 * np.arange(4, dtype=np.uint32)))[
+                    None, :, None
+                ]
+            ).sum(axis=1).astype(np.uint32).view(np.int32)
+            count_h2d(words.nbytes, "deflate_payload")
+            words_dev = jnp.asarray(words)
         c, cl, okg = _encode_group(
-            jnp.asarray(words), lens[g0:g1], n, g, out_bytes,
-            bool(interpret),
+            words_dev, lens[g0:g1], n, g, out_bytes, bool(interpret)
         )
         comp[g0:g1] = c
         clens[g0:g1] = cl
@@ -702,13 +713,14 @@ def deflate_lanes_stream(
     for g0 in range(0, B, LANES):
         g1 = min(B, g0 + LANES)
         n = g1 - g0
-        offs_p = np.zeros(LANES, dtype=np.int32)
-        lens_p = np.zeros(LANES, dtype=np.int32)
-        offs_p[:n] = offs[g0:g1]
-        lens_p[:n] = lens[g0:g1]
-        words = _words_from_stream(
-            dev, jnp.asarray(offs_p), jnp.asarray(lens_p), g["w"]
-        )
+        with span("flate.deflate.match"):
+            offs_p = np.zeros(LANES, dtype=np.int32)
+            lens_p = np.zeros(LANES, dtype=np.int32)
+            offs_p[:n] = offs[g0:g1]
+            lens_p[:n] = lens[g0:g1]
+            words = _words_from_stream(
+                dev, jnp.asarray(offs_p), jnp.asarray(lens_p), g["w"]
+            )
         c, cl, okg = _encode_group(
             words, lens[g0:g1], n, g, out_bytes, bool(interpret)
         )
@@ -751,11 +763,11 @@ def bench_deflate_marginal(
         plens = jnp.full((1, LANES), n_bytes, jnp.int32)
         args = (words, plens, g["w"], g["h"], g["n_chunks"],
                 g["tok_tile"], g["chunk"], g["t_step"], False)
-        jax.block_until_ready(_launch(*args))
+        jax.block_until_ready(_deflate_match_kernel(*args))
         ts = []
         for _ in range(3):
             t0 = time.perf_counter()
-            jax.block_until_ready(_launch(*args))
+            jax.block_until_ready(_deflate_match_kernel(*args))
             ts.append(time.perf_counter() - t0)
         return min(ts)
 

@@ -136,7 +136,7 @@ def _kernel_factory(R: int, T: int):
 @functools.partial(
     jax.jit, static_argnames=("r_words", "t_waves", "interpret")
 )
-def _launch(streams, nbits, r_words: int, t_waves: int, interpret: bool):
+def _inflate_fixed_kernel(streams, nbits, r_words: int, t_waves: int, interpret: bool):
     kernel = _kernel_factory(r_words, t_waves)
     out_rows = -(-t_waves // 4) + 1
     return pl.pallas_call(
@@ -212,7 +212,7 @@ def inflate_fixed_literal(
         ).sum(axis=1).astype(np.uint32).view(np.int32)
         nbits = np.zeros((1, LANES), dtype=np.int32)
         nbits[0, :n] = clens[g0:g1] * 8
-        o, cnt, okk = _launch(
+        o, cnt, okk = _inflate_fixed_kernel(
             jnp.asarray(words), jnp.asarray(nbits), r_words, t_waves,
             bool(interpret),
         )

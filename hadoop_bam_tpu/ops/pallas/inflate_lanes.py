@@ -75,6 +75,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...utils.backend import pallas_interpret
+from ...utils.tracing import span
 
 from ..flate import CLC_ORDER, DIST_BASE, DIST_EXTRA, LEN_BASE, LEN_EXTRA
 
@@ -861,7 +862,7 @@ def _kernel_factory(
         "max_blocks", "max_far", "far_dist", "interpret",
     ),
 )
-def _launch(
+def _inflate_lanes_kernel(
     streams, nbits, isizes, r_words: int, oc_rows: int, ring_rows: int,
     n_chunks: int, t_step: int, max_blocks: int, max_far: int,
     far_dist: int, interpret: bool,
@@ -1030,60 +1031,66 @@ def _inflate_lanes_impl(
     for g0 in range(0, B, LANES):
         g1 = min(B, g0 + LANES)
         n = g1 - g0
-        # Transpose the group: member j's words go down lane j.
-        grp = np.zeros((r_words * 4, LANES), dtype=np.uint8)
-        grp[:C, :n] = comp[g0:g1].T
-        words = (
-            grp.reshape(r_words, 4, LANES).astype(np.uint32)
-            * (np.uint32(1) << (8 * np.arange(4, dtype=np.uint32)))[
-                None, :, None
-            ]
-        ).sum(axis=1).astype(np.uint32).view(np.int32)
-        nbits = np.zeros((1, LANES), dtype=np.int32)
-        nbits[0, :n] = clens[g0:g1] * 8
-        isz = np.zeros((1, LANES), dtype=np.int32)
-        isz[0, :n] = isizes[g0:g1]
-        o, nout, okk, farc, fara, farb = _launch(
-            jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(isz),
-            r_words, oc_rows, geo["ring_rows"], n_chunks, geo["t_step"],
-            max_blocks, max_far, geo["far_dist"], bool(interpret),
-        )
-        by = np.asarray(o).view(np.uint32)
-        bytes_mat = np.zeros((out_cap, LANES), dtype=np.uint8)
-        for k in range(4):
-            bytes_mat[k::4] = ((by >> np.uint32(8 * k)) & 0xFF).astype(
-                np.uint8
+        with span("flate.inflate.pack"):
+            # Transpose the group: member j's words go down lane j.
+            grp = np.zeros((r_words * 4, LANES), dtype=np.uint8)
+            grp[:C, :n] = comp[g0:g1].T
+            words = (
+                grp.reshape(r_words, 4, LANES).astype(np.uint32)
+                * (np.uint32(1) << (8 * np.arange(4, dtype=np.uint32)))[
+                    None, :, None
+                ]
+            ).sum(axis=1).astype(np.uint32).view(np.int32)
+            nbits = np.zeros((1, LANES), dtype=np.int32)
+            nbits[0, :n] = clens[g0:g1] * 8
+            isz = np.zeros((1, LANES), dtype=np.int32)
+            isz[0, :n] = isizes[g0:g1]
+            args = jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(isz)
+        with span("flate.inflate.launch"):
+            o, nout, okk, farc, fara, farb = _inflate_lanes_kernel(
+                *args, r_words, oc_rows, geo["ring_rows"], n_chunks,
+                geo["t_step"], max_blocks, max_far, geo["far_dist"],
+                bool(interpret),
             )
-        nout = np.asarray(nout)[0]
-        okk = np.asarray(okk)[0].astype(bool)
-        farc = np.asarray(farc)[0]
-        fara = np.asarray(fara)
-        farb = np.asarray(farb)
-        for j in range(n):
-            i = g0 + j
-            okj = okk[j] and int(nout[j]) == int(isizes[i])
-            ok_all[i] = okj
-            if okj:
-                lane = bytes_mat[: isizes[i], j].copy()
-                if farc[j]:
-                    _apply_far_copies(
-                        lane, fara[:, j], farb[:, j], int(farc[j])
-                    )
-                out[i, : isizes[i]] = lane
+        with span("flate.inflate.wait"):
+            by = np.asarray(o).view(np.uint32)
+            nout = np.asarray(nout)[0]
+            okk = np.asarray(okk)[0].astype(bool)
+            farc = np.asarray(farc)[0]
+            fara = np.asarray(fara)
+            farb = np.asarray(farb)
+        with span("flate.inflate.verify"):
+            bytes_mat = np.zeros((out_cap, LANES), dtype=np.uint8)
+            for k in range(4):
+                bytes_mat[k::4] = ((by >> np.uint32(8 * k)) & 0xFF).astype(
+                    np.uint8
+                )
+            for j in range(n):
+                i = g0 + j
+                okj = okk[j] and int(nout[j]) == int(isizes[i])
+                ok_all[i] = okj
+                if okj:
+                    lane = bytes_mat[: isizes[i], j].copy()
+                    if farc[j]:
+                        _apply_far_copies(
+                            lane, fara[:, j], farb[:, j], int(farc[j])
+                        )
+                    out[i, : isizes[i]] = lane
         if dev_groups is not None:
             if bool(ok_all[g0:g1].all()) and int(farc[:n].sum()) == 0:
                 # On-chip output residency: this group's device byte view
                 # is exact (no host-side far-copy patches).
-                dev_groups.append(_unpack_device(o)[:n])
+                with span("flate.inflate.flatten"):
+                    dev_groups.append(_unpack_device(o)[:n])
             else:
                 dev_groups = None
     dev = None
     if dev_groups:
         # The caller can feed the device-parse chain without a d2h→h2d
         # bounce: one member-major view over every group.
-        dev = (
-            dev_groups[0]
-            if len(dev_groups) == 1
-            else jnp.concatenate(dev_groups)
-        )
+        if len(dev_groups) == 1:
+            dev = dev_groups[0]
+        else:
+            with span("flate.inflate.flatten"):
+                dev = jnp.concatenate(dev_groups)
     return out, ok_all, dev

@@ -276,7 +276,7 @@ def _kernel_factory(R_WORDS: int, NCB: int, OC_WORDS: int):
     jax.jit,
     static_argnames=("r_words", "ncb", "oc_words", "n_chunks", "interpret"),
 )
-def _launch(
+def _rans_lanes_kernel(
     streams, meta, fbank, cbank, cmap,
     r_words: int, ncb: int, oc_words: int, n_chunks: int, interpret: bool,
 ):
@@ -409,7 +409,7 @@ def _launch_group(group, outs, stats, chunk_bytes, interpret):
             None, :, None
         ]
     ).sum(axis=1).astype(np.uint32).view(np.int32)
-    owords, okk = _launch(
+    owords, okk = _rans_lanes_kernel(
         jnp.asarray(words), jnp.asarray(meta), jnp.asarray(fbank),
         jnp.asarray(cbank), jnp.asarray(cmap),
         r_words, ncb, oc_words, n_chunks, interpret,

@@ -302,7 +302,7 @@ def _kernel_factory(n_words: int, rec_cap: int):
 @functools.partial(
     jax.jit, static_argnames=("n_words", "rec_cap", "interpret")
 )
-def _launch(meta, words, n_words: int, rec_cap: int, interpret: bool):
+def _record_scan_kernel(meta, words, n_words: int, rec_cap: int, interpret: bool):
     kernel = _kernel_factory(n_words, rec_cap)
     return pl.pallas_call(
         kernel,
@@ -375,7 +375,7 @@ def record_scan(
             continue
         n_words, _ = scan_geometry(max_win, cap)
         meta, words = _pack_windows(group, n_words)
-        recs, mout = _launch(
+        recs, mout = _record_scan_kernel(
             jnp.asarray(meta), jnp.asarray(words),
             n_words=n_words, rec_cap=cap, interpret=bool(interpret),
         )

@@ -89,7 +89,7 @@ def test_inflate_lanes_compiles_at_full_member_geometry(one_chip):
     assert il.accepts(0xFF00, 0x10000) == (True, "")
     assert geo["vmem_bytes"] <= il._VMEM_BUDGET_BYTES
     row = _spec((1, il.LANES), jnp.int32, one_chip)
-    c = il._launch.lower(
+    c = il._inflate_lanes_kernel.lower(
         _spec((geo["r_words"], il.LANES), jnp.int32, one_chip), row, row,
         r_words=geo["r_words"], oc_rows=geo["oc_rows"],
         ring_rows=geo["ring_rows"], n_chunks=geo["n_chunks"],
@@ -106,7 +106,7 @@ def test_deflate_lanes_compiles_at_part_writer_payload(one_chip):
     P = dl._round_up(DEV_LZ_PAYLOAD, dl._DEFAULT_CHUNK)
     assert dl.accepts(DEV_LZ_PAYLOAD) == (True, "")
     g = dl._geometry(P, dl._DEFAULT_CHUNK)
-    c = dl._launch.lower(
+    c = dl._deflate_match_kernel.lower(
         _spec((g["w"], dl.LANES), jnp.int32, one_chip),
         _spec((1, dl.LANES), jnp.int32, one_chip),
         w=g["w"], h=g["h"], n_chunks=g["n_chunks"],

@@ -7,7 +7,7 @@ byte-for-byte.
 
 CI budget contract (see tests/test_stream_codecs.py): the always-on
 cases run the interpret-mode encoder only on payloads ≤ ~3 KiB and all
-share the default chunk geometry (one ``_launch`` compile); the CRC and
+share the default chunk geometry (one ``_deflate_match_kernel`` compile); the CRC and
 gather programs are plain XLA and cheap everywhere.  Full-size blocking
 rides ``slow`` + ``device_write`` (the conftest guard skips it under a
 JAX_PLATFORMS=cpu pin).
