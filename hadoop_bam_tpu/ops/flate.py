@@ -11,8 +11,8 @@ Deflate (compress), device side
     Two tiers.  The top tier is the lockstep-lane Pallas **encoder**
     (ops/pallas/deflate_lanes.py): up to 128 members in the 128 vector
     lanes, each running a greedy hash-head LZ77 match-finder (4-byte
-    hash, two-generation probe chain, min match 4) whose token stream is
-    then bit-packed by the same gather-only emit trick as below — real
+    hash, two-generation probe chain, min match 4) whose token stream a
+    second lanes kernel bit-packs into fixed-Huffman DEFLATE — real
     compression, within ~1.05x of zlib level-1 on BAM-class data, wired
     into the part-write path (``deflate_blocks_device`` /
     ``io.bam.write_part_fast``) behind ``hadoopbam.deflate.lanes`` /

@@ -20,8 +20,7 @@ well.  A Pallas lockstep variant was considered and rejected: the table
 gathers would become O(table)×O(members) one-hot selects per wave (the
 probe-style row-select trick), turning a 4-gather step into a 1024-wide
 reduction — the XLA gather path is strictly better here, which is why
-this member of the kernel family has no ``pallas_call`` (same stance as
-``deflate_lanes._compact_tokens``).
+this member of the kernel family has no ``pallas_call``.
 
 Oracle: ``zlib.crc32`` (tests/test_device_write.py fuzzes empty, 1-byte,
 word-boundary and multi-member batches against it bit-for-bit).

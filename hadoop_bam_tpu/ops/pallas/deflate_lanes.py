@@ -1,15 +1,13 @@
 """Lockstep-lane Pallas DEFLATE *encoder*: LZ77 match-finding on chip,
-HBM-streaming token emit.
+HBM-streaming tokens, a lanes bit pack.
 
 The symmetric counterpart to ops/pallas/inflate_lanes.py, and the removal
 of the last codec stage still host-bound (BENCH_NOTES standing ranking:
 part-write deflate ≈ 38% of host wall at the zlib level-1 ceiling).  Up to
 128 BGZF member payloads ride the 128 vector lanes of one kernel; each
 lane runs a greedy hash-table LZ77 match-finder over its own member, and
-the resulting token streams are bit-packed into fixed-Huffman DEFLATE by
-the same gather-only emit trick :func:`ops.flate.deflate_fixed` uses
-(token bit-lengths → cumsum offsets → per-output-bit searchsorted) —
-lifted from bytes to tokens.
+a second lanes kernel bit-packs each lane's token stream into
+fixed-Huffman DEFLATE.
 
 Architecture (probe/inflate register/VMEM-resident style — per-lane row
 selects are dense iota-compare column reductions, never gathers):
@@ -35,11 +33,12 @@ selects are dense iota-compare column reductions, never gathers):
   per-lane cursor/match state persists in scratch, so a match may start
   in one chunk and emit in the next — only the token *tiles* are bounded,
   never the member;
-- the ragged per-chunk token segments are re-compacted device-side (a
-  cumsum + searchsorted + one take_along_axis gather — no host bounce)
-  and the fixed-Huffman bit pack runs as a plain XLA program on the
-  compacted token rows, exactly the :func:`ops.flate.deflate_fixed`
-  shape.
+- the **pack kernel** grids over the same chunks and reads each step's
+  token tile as it stands (no compaction): it walks the rows up to the
+  step's fullest lane, one row load all lanes share, and each lane ORs
+  its token's code into a bit buffer carried across steps, writing whole
+  32-bit words to its next row of the step's word tile.  The host joins
+  each lane's per-step word segments while it fills the output rows.
 
 A full-size BGZF member payload (up to ``_MAX_MEMBER`` = 64 KiB, which
 covers the ~57 KiB ``DEV_MAX_PAYLOAD`` blocking the part writer uses) now
@@ -124,8 +123,8 @@ def _round_up(n: int, m: int) -> int:
 
 def _geometry(P: int, chunk: int) -> dict:
     """Static launch geometry for a member capacity ``P`` (a multiple of
-    ``chunk``): resident stream words, hash rows, token tile rows, grid
-    depth and the per-step wave budget."""
+    ``chunk``): resident stream words, hash rows, token and word tile
+    rows, grid depth and the per-step wave budget."""
     W = P // 4 + 8
     H = min(_HASH_ROWS, max(256, P))
     n_chunks = max(1, P // chunk)
@@ -136,6 +135,7 @@ def _geometry(P: int, chunk: int) -> dict:
         "h": H,
         "n_chunks": n_chunks,
         "tok_tile": tok_tile,
+        "word_tile": _word_tile_rows(chunk),
         "t_step": t_step,
         "chunk": chunk,
     }
@@ -387,141 +387,220 @@ def _deflate_match_kernel(streams, plens, w: int, h: int, n_chunks: int,
 
 
 # --------------------------------------------------------------------------
-# Ragged token compaction + fixed-Huffman bit pack: plain XLA on the
-# kernel's chunked token tiles, device-to-device — tokens never bounce
-# through the host.
+# Fixed-Huffman pack: a second lanes kernel over the match kernel's token
+# tiles, as they stand (no compaction), bit-packing each lane's codes.
 # --------------------------------------------------------------------------
 
-
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _compact_tokens(tok_flat: jax.Array, cnts: jax.Array, tok_tile: int,
-                    T: int) -> jax.Array:
-    """Gather the per-chunk ragged token segments into dense per-lane
-    rows.
-
-    ``tok_flat``: int32 [n_chunks*tok_tile, 128] (chunk k's tokens for
-    lane j at rows [k*tok_tile, k*tok_tile+cnts[k,j])), ``cnts``: int32
-    [n_chunks, 128].  Returns int32 [128, T] (rows = lanes; garbage past
-    each lane's total count — the emit masks by ntok)."""
-    cum = jnp.cumsum(cnts, axis=0)  # [n_chunks, 128]
-    t = jnp.arange(T, dtype=jnp.int32)
-    # Chunk holding token t of each lane, then its offset inside it.
-    ch = jax.vmap(functools.partial(jnp.searchsorted, side="right"))(
-        cum.T, jnp.broadcast_to(t, (LANES, T))
-    ).astype(jnp.int32)  # [128, T]
-    n_chunks = cnts.shape[0]
-    ch_c = jnp.clip(ch, 0, n_chunks - 1)
-    prev = jnp.where(
-        ch_c > 0,
-        jnp.take_along_axis(
-            cum.T, jnp.maximum(ch_c - 1, 0), axis=1
-        ),
-        0,
-    )
-    row = ch_c * tok_tile + (t[None, :] - prev)
-    row = jnp.clip(row, 0, tok_flat.shape[0] - 1)
-    return jnp.take_along_axis(tok_flat, row.T, axis=0).T
+# Packed per-lane registers of the pack kernel's ``st`` scratch bank.
+_P_ACC = 0    # bit buffer: its low _P_NACC bits are pending output
+_P_NACC = 1   # pending bits, 0..31
+_P_BITS = 2   # member bits so far (header, codes, EOB)
 
 
-def _rev_var(code, n, width: int):
-    """Bit-reverse the low ``width`` bits of ``code``, then keep the top
-    ``n`` of them: MSB-first Huffman codes → LSB-first stream patterns."""
+def _word_tile_rows(chunk: int) -> int:
+    """Rows of one step's word tile.  A lane's tokens of one step cover at
+    most ``chunk + MAX_MATCH`` bytes (a match started before the chunk end
+    runs past it) at ≤ 9 bits a byte; add the ≤ 31 bits carried in, the
+    EOB and the final partial word of the last step."""
+    return _round_up((31 + 9 * (chunk + MAX_MATCH) + 7) // 32 + 2, 8)
+
+
+def _rev_bits(code, width: int):
+    """Bit-reverse the low ``width`` bits of ``code``: MSB-first Huffman
+    codes → LSB-first stream order."""
     r = jnp.zeros_like(code)
     for k in range(width):
         r = r | (((code >> k) & 1) << (width - 1 - k))
-    return r >> (width - n)
+    return r
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _emit_tokens_fixed(tokens: jax.Array, ntok: jax.Array, out_bytes: int):
-    """Pack token streams into final fixed-Huffman DEFLATE members.
+def _code_index(x, base: np.ndarray, extra: np.ndarray):
+    """RFC 1951 §3.2.5 length or distance code of ``x``: its index, base
+    and extra-bit count, by compare-sums over the table's increasing base
+    constants (the last base ≤ x wins) — no gather."""
+    idx = jnp.zeros_like(x)
+    b = jnp.full_like(x, int(base[0]))
+    e = jnp.full_like(x, int(extra[0]))
+    for i in range(1, len(base)):
+        ge = x >= int(base[i])
+        idx = jnp.where(ge, i, idx)
+        b = jnp.where(ge, int(base[i]), b)
+        if extra[i] != extra[i - 1]:
+            e = jnp.where(ge, int(extra[i]), e)
+    return idx, b, e
 
-    ``tokens``: int32 [b, T] packed (lit: byte value; copy:
-    ``(1<<30)|(len<<16)|dist``), ``ntok``: int32 [b] live token counts
-    (the EOB is appended at index ntok, so T must be ≥ max(ntok)+1).
-    Returns (words uint32 [b, out_bytes // 4 + 2], clens int32 [b]):
-    each row's bytes are its words' little-endian bytes (viewed on the
-    host — a ``[..., 4]`` byte view on a TPU would be tiled 32x).
-    """
-    b, T = tokens.shape
-    len_base = jnp.asarray(LEN_BASE)
-    len_extra = jnp.asarray(LEN_EXTRA)
-    dist_base = jnp.asarray(DIST_BASE)
-    dist_extra = jnp.asarray(DIST_EXTRA)
 
-    is_cpy = (tokens >> 30) & 1 == 1
-    v = tokens & 0xFF
-    L = (tokens >> 16) & 0x1FF
-    D = tokens & 0xFFFF
-    # Literal codeword (RFC 1951 §3.2.6).
+def _fixed_code(tok):
+    """A token's fixed-Huffman bits (RFC 1951 §3.2.6), LSB first, and
+    their count (≤ 31).  ``tok``: int32 (lit: byte value; copy:
+    ``(1<<30)|(len<<16)|dist``)."""
+    is_cpy = tok >= (1 << 30)
+    v = tok & 0xFF
     lit_hi = v >= 144
-    lit_code = jnp.where(lit_hi, 0x190 + (v - 144), 0x30 + v)
     lit_n = jnp.where(lit_hi, 9, 8)
-    pat_lit = _rev_var(lit_code, lit_n, 9)
-    # Copy: length code + extra, 5-bit distance code + extra.
-    li = jnp.clip(
-        jnp.searchsorted(len_base, L, side="right").astype(jnp.int32) - 1,
-        0,
-        28,
-    )
-    sym_l = 257 + li
-    len_code = jnp.where(sym_l <= 279, sym_l - 256, 0xC0 + (sym_l - 280))
-    len_n = jnp.where(sym_l <= 279, 7, 8)
-    e1 = len_extra[li]
-    ev1 = jnp.clip(L - len_base[li], 0, None)
-    di = jnp.clip(
-        jnp.searchsorted(dist_base, D, side="right").astype(jnp.int32) - 1,
-        0,
-        29,
-    )
-    e2 = dist_extra[di]
-    ev2 = jnp.clip(D - dist_base[di], 0, None)
+    pat_lit = _rev_bits(
+        jnp.where(lit_hi, 0x190 + (v - 144), 0x30 + v), 9
+    ) >> (9 - lit_n)
+    L = (tok >> 16) & 0x1FF
+    D = tok & 0xFFFF
+    li, lbase, e1 = _code_index(L, LEN_BASE, LEN_EXTRA)
+    di, dbase, e2 = _code_index(D, DIST_BASE, DIST_EXTRA)
+    # Length symbols 257..279 take 7 bits (0000000 + sym-256), 280..285
+    # take 8 (11000000 + sym-280).
+    short = li <= 22
+    len_n = jnp.where(short, 7, 8)
+    len_code = jnp.where(short, li + 1, 0xC0 + (li - 23))
     pat_cpy = (
-        _rev_var(len_code, len_n, 8)
-        | (ev1 << len_n)
-        | (_rev_var(di, jnp.full_like(di, 5), 5) << (len_n + e1))
-        | (ev2 << (len_n + e1 + 5))
+        (_rev_bits(len_code, 8) >> (8 - len_n))
+        | ((L - lbase) << len_n)
+        | (_rev_bits(di, 5) << (len_n + e1))
+        | ((D - dbase) << (len_n + e1 + 5))
     )
-    nbits_tok = jnp.where(is_cpy, len_n + e1 + 5 + e2, lit_n)
-    t = jnp.arange(T, dtype=jnp.int32)[None, :]
-    live = t < ntok[:, None]
-    eob = t == ntok[:, None]
-    nbits = jnp.where(live, nbits_tok, jnp.where(eob, 7, 0))
-    pattern = jnp.where(
-        live, jnp.where(is_cpy, pat_cpy, pat_lit), 0
-    ).astype(jnp.uint32)
+    return (
+        jnp.where(is_cpy, pat_cpy, pat_lit),
+        jnp.where(is_cpy, len_n + e1 + 5 + e2, lit_n),
+    )
 
-    # Each token's code (≤ 31 bits) lands at its bit offset, split over
-    # the two 32-bit words it may straddle.  Codes never overlap, so a
-    # scatter-add of the shifted halves is their OR: one update per
-    # token half, not one binary search per output bit.
-    off = jnp.cumsum(nbits, axis=1) - nbits + 3  # 3 header bits first
-    nbits_total = 3 + jnp.sum(nbits, axis=1)
-    NW = -(-out_bytes // 4) + 1
-    w = off >> 5
-    sh = (off & 31).astype(jnp.uint32)
-    lo = pattern << sh
-    hi = jnp.where(sh == 0, jnp.uint32(0), pattern >> (32 - sh))
-    rows = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None], (b, T))
-    words = jnp.zeros((b, NW + 1), jnp.uint32)
-    words = words.at[rows, w].add(lo, mode="drop")
-    words = words.at[rows, w + 1].add(hi, mode="drop")
-    # Header: BFINAL=1, BTYPE=01 (fixed Huffman), LSB first.
-    words = words.at[:, 0].add(jnp.uint32(3))
-    return words, (nbits_total + 7) // 8
+
+def _pack_factory(WT: int, N_CHUNKS: int):
+    """Per grid step, walk the step's token rows up to the fullest lane's
+    count, one dense row load all lanes share per row.  Each live token's
+    code is ORed into its lane's bit buffer; a buffer that reaches 32 bits
+    writes one word to the lane's next row of the step's word tile (a
+    token is ≤ 31 bits: ≤ 1 word per row).  Lane cursors differ, so the
+    store is a one-hot row select over the tile.  The buffer crosses grid
+    steps in scratch; the 3 header bits go in at step 0, the EOB and the
+    final partial word at the last step."""
+
+    def kernel(tok_ref, cnt_ref, words_ref, wcnt_ref, clen_ref, st_ref):
+        k = pl.program_id(0)
+        rows_w = lax.broadcasted_iota(jnp.int32, (WT, LANES), 0)
+        rows_st = lax.broadcasted_iota(jnp.int32, (_ST_ROWS, LANES), 0)
+
+        @pl.when(k == 0)
+        def _init():
+            # Header: BFINAL=1, BTYPE=01 (fixed Huffman), LSB first.
+            st_ref[:, :] = jnp.where(rows_st <= _P_BITS, 3, 0)
+
+        cnt = cnt_ref[0:1, :]
+        st = st_ref[:, :]
+
+        def push(carry, pat, nb):
+            acc, nacc, wcur, bits = carry
+            cat = acc.astype(jnp.uint32) | (
+                pat.astype(jnp.uint32) << nacc.astype(jnp.uint32)
+            )
+            tot = nacc + nb
+            full = tot >= 32
+            words_ref[:, :] = jnp.where(
+                rows_w == jnp.where(full, wcur, -1),
+                cat.astype(jnp.int32),
+                words_ref[:, :],
+            )
+            spill = pat.astype(jnp.uint32) >> (32 - nacc).astype(jnp.uint32)
+            acc = jnp.where(full, spill, cat).astype(jnp.int32)
+            return (
+                acc,
+                jnp.where(full, tot - 32, tot),
+                wcur + full.astype(jnp.int32),
+                bits + nb,
+            )
+
+        def row(r, carry):
+            live = r < cnt
+            pat, nb = _fixed_code(tok_ref[pl.ds(r, 1), :])
+            return push(
+                carry, jnp.where(live, pat, 0), jnp.where(live, nb, 0)
+            )
+
+        zero = jnp.zeros((1, LANES), jnp.int32)
+        carry = lax.fori_loop(
+            0,
+            jnp.max(cnt),
+            row,
+            (
+                st[_P_ACC : _P_ACC + 1, :],
+                st[_P_NACC : _P_NACC + 1, :],
+                zero,
+                st[_P_BITS : _P_BITS + 1, :],
+            ),
+        )
+        # Last step: the EOB (code 256: seven zero bits), then zero bits
+        # up to the word boundary, which writes the final partial word.
+        last = (zero + k) == N_CHUNKS - 1
+        carry = push(carry, zero, jnp.where(last, 7, 0))
+        acc, nacc, wcur, bits = carry
+        acc, nacc, wcur, _ = push(
+            carry, zero, jnp.where(last & (nacc > 0), 32 - nacc, 0)
+        )
+
+        stw = jnp.zeros((_ST_ROWS, LANES), jnp.int32)
+        for r, v in ((_P_ACC, acc), (_P_NACC, nacc), (_P_BITS, bits)):
+            stw = jnp.where(rows_st == r, jnp.broadcast_to(v, stw.shape), stw)
+        st_ref[:, :] = stw
+        # Row 0: words written this step; the other rows: its tokens.
+        wcnt_ref[:, :] = jnp.where(
+            lax.broadcasted_iota(jnp.int32, (_CNT_ROWS, LANES), 0) == 0,
+            jnp.broadcast_to(wcur, (_CNT_ROWS, LANES)),
+            jnp.broadcast_to(cnt, (_CNT_ROWS, LANES)),
+        )
+        clen_ref[:, :] = (bits + 7) // 8
+
+    return kernel
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("tok_tile", "word_tile", "n_chunks", "lanes_out",
+                     "interpret"),
+)
+def _deflate_pack_kernel(toks, cnts, tok_tile: int, word_tile: int,
+                         n_chunks: int, lanes_out: int, interpret: bool):
+    """Fixed-Huffman DEFLATE members from the match kernel's outputs as
+    they stand: ``toks`` [n_chunks*tok_tile, 128] token tiles and
+    ``cnts`` [n_chunks*8, 128] count rows.  Returns (words int32
+    [lanes_out, n_chunks*word_tile], lane-major: lane j's step-k words
+    at ``[j, k*word_tile : k*word_tile + wcnt]``, only the first
+    ``lanes_out`` lanes, so a small group fetches little; counts int32
+    [n_chunks*8, 128]: each step's words in row 0, its tokens in the
+    other rows; clens int32 [1, 128])."""
+    words, counts, clens = pl.pallas_call(
+        _pack_factory(word_tile, n_chunks),
+        grid=(n_chunks,),
+        in_specs=[
+            pl.BlockSpec(
+                (tok_tile, LANES), lambda k: (k, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(
+                (_CNT_ROWS, LANES), lambda k: (k, 0), memory_space=pltpu.VMEM
+            ),
+        ],
+        out_specs=(
+            pl.BlockSpec(
+                (word_tile, LANES), lambda k: (k, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(
+                (_CNT_ROWS, LANES), lambda k: (k, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(
+                (1, LANES), lambda k: (0, 0), memory_space=pltpu.VMEM
+            ),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((n_chunks * word_tile, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((n_chunks * _CNT_ROWS, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((1, LANES), jnp.int32),
+        ),
+        scratch_shapes=[pltpu.VMEM((_ST_ROWS, LANES), jnp.int32)],
+        interpret=interpret,
+    )(toks, cnts)
+    return words.T[:lanes_out], counts, clens
 
 
 def _out_bytes(P: int) -> int:
     """Static output width: literals cost ≤9 bits/byte and copies strictly
     less per covered byte, so the deflate_fixed bound holds for tokens."""
     return (3 + 9 * P + 7 + 7) // 8 + 1
-
-
-def _pow2_at_least(n: int, lo: int) -> int:
-    v = lo
-    while v < n:
-        v *= 2
-    return v
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
@@ -547,50 +626,66 @@ def _encode_group(
     words_dev, plens_np: np.ndarray, n: int, g: dict, out_bytes: int,
     interpret: bool,
 ):
-    """Match-kernel launch + device token compaction + fixed-Huffman pack
-    for one ≤128-lane group whose words are already in the transposed
-    layout (host- or device-built).  Returns (comp [n, out_bytes] uint8,
-    clens int32 [n], ok bool [n]) as host arrays — only the compressed
-    rows come back d2h.  Launches sit in ``flate.deflate.match`` /
-    ``.emit`` spans and each block of fetches in ``flate.deflate.wait``,
-    so a device trace charges kernel runs and host waits apart."""
-    from ...utils.tracing import count_d2h
-    from ..flate import _MAX_LAUNCH_ELEMS
+    """Match-kernel launch + fixed-Huffman pack launch for one ≤128-lane
+    group whose words are already in the transposed layout (host- or
+    device-built).  Returns (comp [n, out_bytes] uint8, clens int32 [n],
+    ok bool [n]) as host arrays.  The launches sit in
+    ``flate.deflate.match`` / ``.emit`` spans and the one block of fetches
+    in ``flate.deflate.wait``, so a device trace charges kernel runs and
+    host waits apart."""
+    from ...utils.tracing import METRICS, count_d2h
+    from ..flate import _pow2_at_least
 
     plens = np.zeros((1, LANES), dtype=np.int32)
     plens[0, :n] = plens_np
     with span("flate.deflate.match"):
-        toks, cnts, ntok, okk = _deflate_match_kernel(
+        toks, cnts, _, okk = _deflate_match_kernel(
             words_dev, jnp.asarray(plens), g["w"], g["h"], g["n_chunks"],
             g["tok_tile"], g["chunk"], g["t_step"], bool(interpret),
         )
-    with span("flate.deflate.wait"):
-        ntok_np = np.asarray(ntok)[0]
-    T = _pow2_at_least(int(ntok_np.max()) + 1, 256)
     with span("flate.deflate.emit"):
-        tok_bt = _compact_tokens(toks, cnts[::_CNT_ROWS], g["tok_tile"], T)
-        ntok_vec = ntok[0]
-    # Two scatter updates per token: keep each launch under the cap.
-    emit_step = max(1, _MAX_LAUNCH_ELEMS // (2 * T))
-    comp = np.zeros((n, out_bytes), dtype=np.uint8)
-    clens = np.zeros(n, dtype=np.int32)
-    for r0 in range(0, n, emit_step):
-        r1 = min(n, r0 + emit_step)
-        with span("flate.deflate.emit"):
-            c, cl = _emit_tokens_fixed(
-                tok_bt[r0:r1], ntok_vec[r0:r1], out_bytes
-            )
-        with span("flate.deflate.wait"):
-            # A TPU may hand the words back in a strided host layout; the
-            # byte view needs contiguous rows.
-            comp[r0:r1] = np.ascontiguousarray(np.asarray(c)).view(
-                np.uint8
-            )[:, :out_bytes]
-            clens[r0:r1] = np.asarray(cl)
-    count_d2h(comp.nbytes, "deflate_comp")
+        # Words come back for n lanes rounded up to a power of two: few
+        # programs to compile, little to fetch for a small group.
+        words, counts, cl = _deflate_pack_kernel(
+            toks, cnts, g["tok_tile"], g["word_tile"], g["n_chunks"],
+            min(_pow2_at_least(n, 8), LANES), bool(interpret),
+        )
     with span("flate.deflate.wait"):
+        # A TPU may hand the words back in a strided host layout; the
+        # masks below need contiguous rows.
+        words_np = np.ascontiguousarray(np.asarray(words))
+        counts_np = np.asarray(counts)
+        clens = np.asarray(cl)[0, :n]
         ok = np.asarray(okk)[0, :n].astype(bool)
-    return comp, clens, ok
+    count_d2h(words_np.nbytes + counts_np.nbytes, "deflate_comp")
+    tcnt = counts_np[1::_CNT_ROWS, :n]  # [n_chunks, n] tokens a step
+    METRICS.count("flate.deflate.pack_tokens", int(tcnt.sum()))
+    METRICS.count("flate.deflate.pack_waves", int(tcnt.max(axis=1).sum()))
+    comp, fits = _join_words(
+        words_np, counts_np, n, g["word_tile"], out_bytes
+    )
+    return comp, clens, ok & fits
+
+
+def _join_words(words_np: np.ndarray, counts_np: np.ndarray, n: int,
+                word_tile: int, out_bytes: int):
+    """Each lane's per-step word segments joined into its output row.
+
+    ``words_np`` / ``counts_np`` are the pack kernel's fetched outputs.
+    Returns (comp uint8 [n, out_bytes], fits bool [n]): a lane whose
+    words overran a step's tile lost them and comes back not ``fits``."""
+    wcnt = counts_np[0::_CNT_ROWS, :n]  # [n_chunks, n] words a step
+    fits = (wcnt <= word_tile).all(axis=0)
+    wcnt = np.minimum(wcnt, word_tile)
+    seg = words_np.reshape(-1, wcnt.shape[0], word_tile)
+    out_words = max(-(-out_bytes // 4), int(wcnt.sum(axis=0).max()))
+    comp_w = np.zeros((n, out_words), dtype=np.int32)
+    for j, lane_counts in enumerate(wcnt.T.tolist()):
+        p = 0
+        for k, c in enumerate(lane_counts):
+            comp_w[j, p : p + c] = seg[j, k, :c]
+            p += c
+    return comp_w.view(np.uint8)[:, :out_bytes], fits
 
 
 def deflate_lanes(
@@ -740,15 +835,16 @@ def deflate_lanes_stream(
 def bench_deflate_marginal(
     p_small: int = 1024, p_big: int = 4096
 ) -> dict:
-    """Marginal per-wave cost of the match kernel via a two-point fit.
+    """Marginal per-wave cost of the match kernel via a two-point fit, and
+    the pack kernel's time on the same two token sets.
 
     Same launch-free protocol as ``inflate_probe.bench_marginal``: one
     geometry (sized for ``p_big``), two live member lengths — the wave
     count tracks the member length on literal-dominated (random) data, so
     the slope is the per-wave cost and the intercept absorbs the launch.
     Reports the literal-path floor (1 byte/lane/wave); matches only go
-    faster.  The XLA bit-pack stage is excluded (it is bandwidth-bound
-    and embarrassingly parallel, not the serial engine being probed).
+    faster.  ``pack_small_ms`` is the pack launch on a small group's
+    tokens (mostly its fixed cost), ``pack_big_ms`` one row a byte.
     """
     import time
 
@@ -759,20 +855,30 @@ def bench_deflate_marginal(
         rng.integers(0, 1 << 31, (g["w"], LANES), dtype=np.int32)
     )
 
-    def timed(n_bytes: int) -> float:
-        plens = jnp.full((1, LANES), n_bytes, jnp.int32)
-        args = (words, plens, g["w"], g["h"], g["n_chunks"],
-                g["tok_tile"], g["chunk"], g["t_step"], False)
-        jax.block_until_ready(_deflate_match_kernel(*args))
+    def timed(fn) -> float:
+        jax.block_until_ready(fn())
         ts = []
         for _ in range(3):
             t0 = time.perf_counter()
-            jax.block_until_ready(_deflate_match_kernel(*args))
+            jax.block_until_ready(fn())
             ts.append(time.perf_counter() - t0)
         return min(ts)
 
-    dt_s = timed(p_small)
-    dt_b = timed(p_big)
+    def match_and_pack(n_bytes: int):
+        plens = jnp.full((1, LANES), n_bytes, jnp.int32)
+        args = (words, plens, g["w"], g["h"], g["n_chunks"],
+                g["tok_tile"], g["chunk"], g["t_step"], False)
+        toks, cnts, _, _ = _deflate_match_kernel(*args)
+        return (
+            timed(lambda: _deflate_match_kernel(*args)),
+            timed(lambda: _deflate_pack_kernel(
+                toks, cnts, g["tok_tile"], g["word_tile"], g["n_chunks"],
+                LANES, False,
+            )),
+        )
+
+    dt_s, pack_s = match_and_pack(p_small)
+    dt_b, pack_b = match_and_pack(p_big)
     per_wave = (dt_b - dt_s) / (p_big - p_small)
     fixed = dt_s - per_wave * p_small
     bytes_per_s = LANES / per_wave if per_wave > 0 else float("inf")
@@ -783,6 +889,8 @@ def bench_deflate_marginal(
         "projected_mb_s": bytes_per_s / 1e6,
         "t_small_ms": dt_s * 1e3,
         "t_big_ms": dt_b * 1e3,
+        "pack_small_ms": pack_s * 1e3,
+        "pack_big_ms": pack_b * 1e3,
     }
 
 

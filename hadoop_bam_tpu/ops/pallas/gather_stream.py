@@ -12,8 +12,8 @@ duplicate records get ``FLAG_DUPLICATE`` ORed into their two flag bytes
 (body offset 14 → bytes 18/19 past the size word) — no host bounce of the
 payload.
 
-Formulation notes (why this kernel-family member is an XLA program, like
-``deflate_lanes._compact_tokens``): the source offset ``src0[r] - dst0[r]``
+Formulation notes (why this kernel-family member is an XLA program):
+the source offset ``src0[r] - dst0[r]``
 is constant along a record, so each output tile scatters the offset's
 *change* at every record start and one ``cumsum`` spreads it over the
 tile: per output byte one cumsum step and one gather from the stream — no

@@ -116,6 +116,23 @@ def test_deflate_lanes_compiles_at_part_writer_payload(one_chip):
     assert _is_kernel(c)
 
 
+def test_deflate_pack_compiles_at_part_writer_payload(one_chip):
+    """The fixed-Huffman pack kernel over the match kernel's token tiles
+    at the part writer's member blocking."""
+    from hadoop_bam_tpu.ops.pallas import deflate_lanes as dl
+
+    P = dl._round_up(DEV_LZ_PAYLOAD, dl._DEFAULT_CHUNK)
+    g = dl._geometry(P, dl._DEFAULT_CHUNK)
+    rows = g["n_chunks"] * g["tok_tile"]
+    c = dl._deflate_pack_kernel.lower(
+        _spec((rows, dl.LANES), jnp.int32, one_chip),
+        _spec((g["n_chunks"] * dl._CNT_ROWS, dl.LANES), jnp.int32, one_chip),
+        tok_tile=g["tok_tile"], word_tile=g["word_tile"],
+        n_chunks=g["n_chunks"], lanes_out=dl.LANES, interpret=False,
+    ).compile()
+    assert _is_kernel(c)
+
+
 def test_keys_and_sort_compile(one_chip):
     """Key assembly + the single-chip key sort."""
     from hadoop_bam_tpu.ops.keys import make_keys

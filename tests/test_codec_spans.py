@@ -92,9 +92,23 @@ def test_inflate_stage_records_every_sub_span(n_members):
 
 
 @pytest.mark.parametrize("device_input", [False, True])
-def test_deflate_stage_records_every_sub_span(device_input):
-    """Three fetch blocks per group (token counts, each emit step's rows,
-    the ok column) plus the CRC column on the device-input path."""
+def test_deflate_stage_records_every_sub_span(device_input, monkeypatch):
+    """Per group: the word build and the match kernel under ``.match``,
+    the pack kernel under ``.emit``, and one fetch block under ``.wait``
+    (the word tiles, their counts, the lengths and the ok column), plus
+    the CRC column's fetch on the device-input path.  The pack counters
+    count the match kernel's tokens and the rows the pack walks."""
+    from hadoop_bam_tpu.ops.pallas import deflate_lanes as dl
+
+    seen = []
+    match = dl._deflate_match_kernel
+
+    def recording_match(*a, **kw):
+        out = match(*a, **kw)
+        seen.append((np.asarray(out[1]), np.asarray(out[2])))
+        return out
+
+    monkeypatch.setattr(dl, "_deflate_match_kernel", recording_match)
     data = _payload(2500, 2)
     kw = dict(level=1, block_payload=1024, use_lanes=True)
     before = snapshot()
@@ -113,16 +127,23 @@ def test_deflate_stage_records_every_sub_span(device_input):
     assert got["flate.stage.deflate_device"] == 1
     # The word build and the match kernel of the one group.
     assert got["flate.deflate.match"] == 2
-    # Token compaction, then one emit step.
-    assert got["flate.deflate.emit"] == 2
-    assert got["flate.deflate.wait"] == 3 + device_input
+    # The pack kernel of the one group.
+    assert got["flate.deflate.emit"] == 1
+    assert got["flate.deflate.wait"] == 1 + device_input
     assert got.get("flate.deflate.crc", 0) == int(device_input)
     assert got["flate.deflate.frame"] == 1
     assert not [k for k in got if k.startswith("flate.inflate.")]
+    ((cnts, ntok),) = seen
+    counters = delta(before)["counters"]
+    assert counters["flate.deflate.pack_tokens"] == int(ntok.sum()) > 0
+    assert counters["flate.deflate.pack_waves"] == int(
+        cnts[:: dl._CNT_ROWS].max(axis=1).sum()
+    )
 
 
 def test_deflate_wait_spans_hold_each_group_fetch():
-    """Two groups: the fetch blocks double, the framing stays one."""
+    """Two groups: two match spans, one pack launch and one fetch block
+    each; the framing stays one."""
     data = _payload(129 * 16, 3)
     before = snapshot()
     flate.deflate_blocks_device(
@@ -130,7 +151,8 @@ def test_deflate_wait_spans_hold_each_group_fetch():
     )
     got = _counts(before)
     assert got["flate.deflate.match"] == 4
-    assert got["flate.deflate.wait"] == 6
+    assert got["flate.deflate.emit"] == 2
+    assert got["flate.deflate.wait"] == 2
     assert got["flate.deflate.frame"] == 1
 
 
@@ -203,7 +225,7 @@ def test_lane_kernels_have_distinct_names():
     assert not dup, dup
     assert "_launch" not in names
     for kernel in ("_inflate_lanes_kernel", "_deflate_match_kernel",
-                   "_inflate_fixed_kernel", "_record_scan_kernel",
+                   "_deflate_pack_kernel", "_inflate_fixed_kernel", "_record_scan_kernel",
                    "_rans_lanes_kernel"):
         assert kernel in names, kernel
 

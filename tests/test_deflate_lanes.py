@@ -11,6 +11,7 @@ rides the ``slow`` mark; the real-chip test rides ``tpu`` +
 ``device_deflate`` (conftest skips it under JAX_PLATFORMS=cpu).
 """
 
+import hashlib
 import io
 import os
 import struct
@@ -18,6 +19,8 @@ import zlib
 
 import numpy as np
 import pytest
+
+import jax.numpy as jnp
 
 from hadoop_bam_tpu.conf import Configuration, DEFLATE_LANES
 from hadoop_bam_tpu.ops import flate
@@ -135,6 +138,172 @@ def test_ratio_bam_like_within_bound_of_zlib1():
     assert r["rel_zlib1"] <= 1.25, r
     # Premise: literal-only fixed-Huffman cannot meet the bound.
     assert 9 / 8 > 1.25 * r["zlib1_ratio"]
+
+
+def _random_members(seed: int, n: int, lo: int, hi: int):
+    """Seeded member payloads of three kinds (random bytes, a 4-letter
+    alphabet, a SAM-text alphabet); member 0 is empty."""
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(lo, hi, n).astype(np.int32)
+    lens[0] = 0
+    alpha = np.frombuffer(b"ACGTN\t:0123", np.uint8)
+    mat = np.zeros((n, int(lens.max())), np.uint8)
+    for i, n_i in enumerate(lens):
+        kind = i % 3
+        if kind == 0:
+            p = rng.integers(0, 256, n_i, dtype=np.uint8)
+        elif kind == 1:
+            p = rng.integers(65, 69, n_i, dtype=np.uint8)
+        else:
+            p = alpha[rng.integers(0, len(alpha), n_i)]
+        mat[i, :n_i] = p
+    return mat, lens
+
+
+def _golden_case(name: str):
+    """(payload rows, lengths, chunk bytes): every member crosses at least
+    two input chunks."""
+    from hadoop_bam_tpu.ops.pallas.deflate_lanes import _bam_like_corpus
+
+    if name == "bam_like":
+        return _bam_like_corpus(6, 2600), np.full(6, 2600, np.int32), 1024
+    if name == "random":
+        return _random_members(2025, 7, 1500, 3300) + (1024,)
+    return _bam_like_corpus(2, 4600), np.full(2, 4600, np.int32), 4096
+
+
+#: Each member's (length, compressed length, SHA-256 of its bytes), as the
+#: XLA token compaction and scatter-add emit wrote them: the pack kernel
+#: must give the same bits for the same tokens.
+GOLDEN = {
+    "bam_like": [
+        (2600, 206, "3f538dac190d8940feaba8cc10337abe7fdd957af078d61503b0c037f9bd1a84"),
+        (2600, 192, "becf9634fc11b9693dfd3287cf6bbbaa808e933a9726a326d81096c127dae48e"),
+        (2600, 201, "0f2477b0ee11f73be8848f0adc216c3fbbd12e17e7e0c840cae1a66aaecfce7d"),
+        (2600, 192, "8e0b76375b3b4bd6a0424604cb04a28313f34abeb1af0a5677a3aafde22747e9"),
+        (2600, 202, "2c9e65a43956fe88c27da7d89b8f11106a8d554547f522d54224dc63d1d338b5"),
+        (2600, 198, "96f34e5b1e5fbbebe2b1e34f8ea9fd7072c2d62b723caaca8983d8c42091fe75"),
+    ],
+    "random": [
+        (0, 2, "9b4fb24edd6d1d8830e272398263cdbf026b97392cc35387b991dc0248a628f9"),
+        (3290, 1937, "c745e0e3f868950cff7d05ad66ad67866b7a4035bfabe75577b450adf3f43b8e"),
+        (3286, 2980, "c7f4e99fb78ffdc6476b03918e0b79108b3534e502e6e92c09350b9cdd1661c4"),
+        (2187, 2305, "6c086346e713a2b1697f75acdbc136c4f69b3f0a42940134081b1f866f19076b"),
+        (3216, 1871, "1b9252cacb5bb575681091038e0724ed20fb1ee49dc3423af03aa0ecffaa8bc8"),
+        (2988, 2703, "18d8c57cf08786b849dbea0ee24119d744b59e2bc75433ece35e7e103d0378a6"),
+        (2651, 2799, "699ccff5652a7c203bb11744d5985cd84baee85d18e09560218fdd6b18e3a57d"),
+    ],
+    "bam_like_4k_chunks": [
+        (4600, 325, "23ebe7af032ecddbfc85fc7cc15e7bde096b386cd7434579eb0c4a0d436693d3"),
+        (4600, 322, "3047930707a84ed7fb338aedb2c078e0feca2ed32755e353f942d7d289b9669f"),
+    ],
+}
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN))
+def test_members_match_golden_bytes(case):
+    """Byte parity with the recorded encoder output, member by member."""
+    mat, lens, chunk = _golden_case(case)
+    comp, clens, ok = deflate_lanes(
+        mat, lens, chunk_bytes=chunk, interpret=True
+    )
+    assert ok.all(), ok
+    got = [
+        (int(lens[i]), int(clens[i]),
+         hashlib.sha256(comp[i, : clens[i]].tobytes()).hexdigest())
+        for i in range(len(lens))
+    ]
+    assert got == GOLDEN[case]
+
+
+# --------------------------------------------------------------------------
+# The pack kernel alone, on hand-built token tiles.
+# --------------------------------------------------------------------------
+
+_PACK_TILE = 40     # token rows a step
+_PACK_CHUNKS = 3
+
+
+def _tok(t) -> int:
+    return t[1] if t[0] == "lit" else (1 << 30) | (t[1] << 16) | t[2]
+
+
+def _straddle_prefix(shift: int):
+    """Literals whose codes (8 bits below 144, 9 from 144) put the next
+    code at bit ``shift`` of a word, after the 3 header bits."""
+    for n9 in range(32):
+        for n8 in range(32):
+            if (3 + 8 * n8 + 9 * n9) % 32 == shift:
+                return [("lit", 65)] * n8 + [("lit", 200)] * n9
+    raise AssertionError(shift)
+
+
+def _pack_lanes():
+    """Per lane: its tokens and how many of them each step's tile holds."""
+    lanes = [
+        ([], [0, 0, 0]),                                     # no tokens
+        ([("lit", 7), ("copy", 4, 1)], [2, 0, 0]),           # ends early
+        ([("lit", 0), ("lit", 143), ("lit", 144), ("lit", 255)], [1, 2, 1]),
+        ([("copy", 4, 1), ("copy", 227, 24577), ("copy", 257, 32768),
+          ("copy", 258, 1), ("lit", 1), ("copy", 258, 32768)], [3, 0, 3]),
+    ]
+    rng = np.random.default_rng(5)
+    # Streams across both tile boundaries, carried bits never aligned.
+    for _ in range(4):
+        toks = []
+        for _ in range(int(rng.integers(60, 3 * _PACK_TILE))):
+            if rng.random() < 0.4:
+                toks.append(("copy", int(rng.integers(3, 259)),
+                             int(rng.integers(1, 32769))))
+            else:
+                toks.append(("lit", int(rng.integers(0, 256))))
+        a = int(rng.integers(len(toks) - 2 * _PACK_TILE, _PACK_TILE + 1))
+        b = int(rng.integers(len(toks) - a - _PACK_TILE, _PACK_TILE + 1))
+        lanes.append((toks, [a, b, len(toks) - a - b]))
+    # A 31-bit code (length 257: 8 + 5 bits, distance 32768: 5 + 13) at
+    # every bit shift of a word, split over the first two steps.
+    for shift in range(32):
+        toks = _straddle_prefix(shift) + [("copy", 257, 32768), ("lit", 9)]
+        lanes.append((toks, [len(toks) - 2, 2, 0]))
+    return lanes
+
+
+def test_pack_kernel_matches_host_encoder():
+    """Hand-built token tiles straight into the pack kernel: every lane's
+    bytes equal ``flate.encode_tokens_fixed`` of its tokens."""
+    from hadoop_bam_tpu.ops.pallas import deflate_lanes as dl
+
+    lanes = _pack_lanes()
+    n = len(lanes)
+    toks = np.zeros((_PACK_CHUNKS * _PACK_TILE, dl.LANES), np.int32)
+    cnts = np.zeros((_PACK_CHUNKS * dl._CNT_ROWS, dl.LANES), np.int32)
+    for j, (tl, split) in enumerate(lanes):
+        assert sum(split) == len(tl) and max(split) <= _PACK_TILE
+        i = 0
+        for k, c in enumerate(split):
+            rows = [_tok(t) for t in tl[i : i + c]]
+            toks[k * _PACK_TILE : k * _PACK_TILE + c, j] = rows
+            cnts[k * dl._CNT_ROWS : (k + 1) * dl._CNT_ROWS, j] = c
+            i += c
+    word_tile = dl._round_up((31 + 31 * _PACK_TILE + 7) // 32 + 2, 8)
+    words, counts, cl = dl._deflate_pack_kernel(
+        jnp.asarray(toks), jnp.asarray(cnts), tok_tile=_PACK_TILE,
+        word_tile=word_tile, n_chunks=_PACK_CHUNKS, lanes_out=dl.LANES,
+        interpret=True,
+    )
+    counts_np = np.asarray(counts)
+    want = [flate.encode_tokens_fixed(tl) for tl, _ in lanes]
+    out_bytes = max(len(w) for w in want)
+    comp, fits = dl._join_words(
+        np.asarray(words), counts_np, n, word_tile, out_bytes
+    )
+    assert fits.all()
+    clens = np.asarray(cl)[0, :n]
+    for j, w in enumerate(want):
+        assert int(clens[j]) == len(w), j
+        assert comp[j, : clens[j]].tobytes() == w, j
+    # Each step's token counts come back beside its word counts.
+    assert (counts_np[1 :: dl._CNT_ROWS, :n] == cnts[:: dl._CNT_ROWS, :n]).all()
 
 
 class TestBgzfCompressDevice:
