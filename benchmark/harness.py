@@ -14,11 +14,17 @@ on one input made in set-up.  A job is started while the window is open and
 always runs to its end: the rate is the records of every job that did not
 fail over the time from the window's start to the end of the last job.
 After the window every job's output is compared with the plain reference.
+
+A run gives no result where the program did not take the cell's path:
+where the warm-up job, the traced job or every job of the window raised,
+exited nonzero or broke a path rule (:func:`failure_reasons`), :func:`run`
+raises :class:`OffPath`.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import importlib.util
 import io
@@ -57,6 +63,14 @@ REQUIRED_ZERO = (
 
 class NoChip(RuntimeError):
     pass
+
+
+class OffPath(RuntimeError):
+    """The program did not take the cell's path: no result is given."""
+
+    def __init__(self, cell: str, job: str, reasons: List[str]) -> None:
+        super().__init__(f"cell {cell}: {job} did not take the cell's path:\n"
+                         + "\n".join(f"  - {r}" for r in reasons))
 
 
 def load_json(path: str) -> dict:
@@ -192,16 +206,27 @@ def run_job(src: str, out: str, argv: List[str]) -> Job:
 
     buf = io.StringIO()
     t0 = time.perf_counter()
+    with contextlib.redirect_stdout(buf):
+        rc = cli.main(command_line(argv, src, out))
+    t1 = time.perf_counter()
+    text = buf.getvalue()
+    if rc != 0:
+        return Job(t0, t1, out, error=f"{argv[0]} exited {rc}: {text[-2000:]}")
+    return Job(t0, t1, out, report=json.loads(text[0 if text.startswith("{") else text.index("\n{") + 1:]))
+
+
+def call_job(job_fn: Callable[[str, str, List[str]], Job], src: str, out: str, argv: List[str]) -> Job:
+    """``job_fn``'s job; an exception it raises becomes the job's error."""
+    t0 = time.perf_counter()
     try:
-        with contextlib.redirect_stdout(buf):
-            rc = cli.main(command_line(argv, src, out))
-        t1 = time.perf_counter()
-        text = buf.getvalue()
-        if rc != 0:
-            return Job(t0, t1, out, error=f"{argv[0]} exited {rc}: {text[-2000:]}")
-        return Job(t0, t1, out, report=json.loads(text[0 if text.startswith("{") else text.index("\n{") + 1:]))
+        return job_fn(src, out, argv)
     except Exception:  # the job failed; the run goes on and reports it
         return Job(t0, time.perf_counter(), out, error=traceback.format_exc()[-4000:])
+
+
+def path_breaks(job: Job, expect: dict) -> List[str]:
+    """Why ``job`` is no run of the cell's path; empty where it is one."""
+    return [f"error: {job.error}"] if job.error else failure_reasons(job.report, expect)
 
 
 def failure_reasons(report: dict, expect: dict) -> List[str]:
@@ -309,7 +334,10 @@ def run(
     device: Optional[dict] = None,
     job_fn: Callable[[str, str, List[str]], Job] = run_job,
 ) -> dict:
-    """One run of ``cell``; returns the result line's object."""
+    """One run of ``cell``; returns the result line's object.  Raises
+    :class:`OffPath` where the program did not take the cell's path: in
+    set-up, before the window opens, where the warm-up job did not."""
+    job_fn = functools.partial(call_job, job_fn)
     watch = CompileWatch()
     t0 = time.time()
     if device is None:
@@ -318,6 +346,7 @@ def run(
     peaks = peaks_for(device["kind"]) if device["platform"] == "tpu" else {}
     tr = cell.traffic
     limits = tr["limits"]
+    expect = tr.get("expect", {})
     gen, ref = cell.generator(), cell.reference()
     argv = list(tr["argv"])
     work_dir = tempfile.mkdtemp(prefix="benchmark-")
@@ -329,8 +358,9 @@ def run(
         corpus_s = time.time() - t0
         t0 = time.time()
         warm = job_fn(src, os.path.join(work_dir, "warmup.out"), argv)
-        if warm.error:
-            raise RuntimeError(f"the warm-up job failed:\n{warm.error}")
+        reasons = path_breaks(warm, expect)
+        if reasons:
+            raise OffPath(cell.name, "the warm-up job", reasons)
         os.remove(warm.out)
         setup = {
             "backend_s": backend_s, "corpus_s": corpus_s, "warmup_s": time.time() - t0,
@@ -360,13 +390,11 @@ def run(
         compared: Dict[bytes, Dict[str, float]] = {}
         for j in jobs:
             data = b""
-            if j.error:
-                _log(f"job failed: {j.error}")
-            else:
+            if not j.error:
                 with open(j.out, "rb") as f:
                     data = f.read()
-                j.reasons = failure_reasons(j.report, tr.get("expect", {}))
-                rule_breaks.update(j.reasons)
+            j.reasons = path_breaks(j, expect)
+            rule_breaks.update(j.reasons)
             # Byte-identical outputs hold the same answer: compare each
             # distinct output once.
             digest = hashlib.blake2b(data, digest_size=16).digest()
@@ -377,14 +405,19 @@ def run(
                 worst[k] = max(worst.get(k, v), v)
             over = any(v > limits[k] for k, v in got.items())
             wrong += bool(j.error or over)
-            j.failed = bool(j.error or over or j.reasons)
+            j.failed = bool(over or j.reasons)
         _log(f"check: {len(compared)} distinct output(s) compared with the reference")
         for r, k in rule_breaks.items():
             _log(f"{k} job(s) failed the path rule: {r}")
-        ok_jobs = [j for j in jobs if j.report is not None]
+        # Only jobs that took the cell's path are read: no rate, and no
+        # per-layer metric, comes from a fallback.
+        on_path = [j for j in jobs if not j.reasons]
+        if not on_path:
+            raise OffPath(cell.name, "the traced job" if traced else f"every job of the window ({len(jobs)})",
+                          [f"{k} job(s): {r}" for r, k in rule_breaks.items()])
         work.update(ref.work(want))
-        ctx = Context(ok_jobs, corpus.n_records, None, work, setup, peaks)
-        if traced and ok_jobs:
+        ctx = Context(on_path, corpus.n_records, None, work, setup, peaks)
+        if traced:
             from . import trace as trace_mod
 
             ctx.trace = trace_mod.reduce(trace_mod.load(xplane))
@@ -393,7 +426,7 @@ def run(
         metrics = {}
         if traced:
             for m in cell.per_layer:
-                v = cell.reader(m["name"])(ctx) if ok_jobs else None
+                v = cell.reader(m["name"])(ctx)
                 if v is not None:
                     metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         else:

@@ -2,10 +2,16 @@
 
     python3 -m benchmark.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Run from the root of a checkout.  Exits 3, with no result, unless JAX finds
-a TPU with as many chips as the cell asks for.  The last line of standard
-output is the result; the last lines of standard error are the numbers
-compared with the reference, each beside its limit.
+Run from the root of a checkout.  The last line of standard output is the
+result; the last lines of standard error are the numbers compared with the
+reference, each beside its limit.  Without a result it exits
+
+- 3 unless JAX finds a TPU with as many chips as the cell asks for;
+- 4 where the program did not take the cell's path: the warm-up job (the
+  run then ends in set-up, before the window opens), the traced job, or
+  every job of the window raised, exited nonzero or broke a path rule.  The
+  reasons are on standard error.  A program that cannot run a cell at all,
+  such as a parent of the change that adds the cell, fails so after one job.
 """
 
 from __future__ import annotations
@@ -56,6 +62,10 @@ def main(argv=None) -> int:
     except harness.NoChip as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 3
+    except harness.OffPath as e:
+        print(f"benchmark: {e}\nbenchmark: no result, {time.time() - T_PROCESS:.3f} s after the process started",
+              file=sys.stderr)
+        return 4
     for k, c in result["checks"].items():
         print(f"check {k}: {c['value']} (limit {c['limit']})", file=sys.stderr)
     print(json.dumps(result), flush=True)

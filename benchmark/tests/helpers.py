@@ -1,6 +1,7 @@
 """Stand-ins for the program's sort in the self-tests: they write an answer
 made by the reference, as it is or broken on purpose."""
 
+import itertools
 import time
 
 import numpy as np
@@ -36,14 +37,17 @@ def answer_job(traffic, make=None, delays=None, backend=None, level=None):
     """A job function: ``make(corpus, traffic)`` gives the ``Expected`` to
     write (default: the reference's own answer), at ``level`` (default: the
     traffic's output level); ``delays`` are slept in turn.  Its report is
-    that of a job on the cell's path, on ``backend`` if given."""
+    that of a job on the cell's path, on ``backend`` if given: a name, or a
+    list of names taken job by job, over and over.  ``job.calls`` counts the
+    jobs."""
     it = iter(delays or [])
     level = traffic["output_level"] if level is None else level
     expect = traffic.get("expect", {})
-    backend = backend or expect.get("backend")
+    backends = itertools.cycle(backend if isinstance(backend, list) else [backend or expect.get("backend")])
     counters = {"mh.shuffle.devices": 4, **{k: 1 for k in expect.get("nonzero", [])}}
 
     def job(src, out, argv):
+        job.calls += 1
         t0 = time.perf_counter()
         time.sleep(next(it, 0.0))
         c = corpus_from_bam(src)
@@ -51,7 +55,8 @@ def answer_job(traffic, make=None, delays=None, backend=None, level=None):
         with open(out, "wb") as f:
             f.write(want.to_bam(level))
         report = {"counters": counters, "span_seconds": {},
-                  "run_manifest": {"backend": backend, "degraded": False}}
+                  "run_manifest": {"backend": next(backends), "degraded": False}}
         return harness.Job(t0, time.perf_counter(), out, report=report)
 
+    job.calls = 0
     return job

@@ -103,7 +103,10 @@ def test_every_control_fails_a_limit(cell):
 
 
 def test_the_program_on_the_cpu_agrees_with_the_reference():
-    """The real sort, on the host path the CPU takes, at a small size."""
+    """The real sort, on the host path the CPU takes, at a small size.  The
+    cell's path rule names the chip's device tiers, which the CPU does not
+    take, so the host path is the one this run expects."""
     c = small("wgs_sort.bgzf6", 3000)
+    c.traffic = dict(c.traffic, expect={"backend": "single-device"})
     r = harness.run(c, 17, 5.0, False, time.time(), device=CPU)
-    assert r["correct"] is True and r["attempted"] >= 1
+    assert r["correct"] is True and r["attempted"] >= 1 and r["failed"] == 0

@@ -40,16 +40,93 @@ def test_the_job_open_at_the_window_end_counts_whole():
 
 
 def test_a_job_off_the_path_adds_no_records():
-    """A job that fell back (here: the wrong backend) is failed, and its
-    records leave the rate; its output is still right."""
+    """Jobs that fell back (here: every other job on the wrong backend) are
+    failed, and their records leave the rate; the jobs on the path count,
+    and the output of all of them is still right."""
     import time
 
     cell = fake_cell()
-    r = harness.run(cell, 6, 0.3, False, time.time(), device=CPU,
-                    job_fn=answer_job(cell.traffic, backend="host-fallback"))
+    inner = answer_job(cell.traffic, delays=[0.1] * 100,
+                       backend=[cell.traffic["expect"]["backend"], "host-fallback"])
+    jobs = []
+
+    def job_fn(src, out, argv):
+        jobs.append(inner(src, out, argv))
+        return jobs[-1]
+
+    r = harness.run(cell, 6, 1.0, False, time.time(), device=CPU, job_fn=job_fn)
+    warm, window = jobs[0], jobs[1:]
+    off = len(window[::2])  # the window's first job is the cycle's second
+    assert r["attempted"] == len(window) >= 3
     assert r["correct"] is True
-    assert r["failed"] == r["attempted"] >= 1
-    assert r["metrics"]["sort_records_per_s"]["value"] == 0
+    assert r["failed"] == off
+    # The window opens after the warm-up's end and before its first job.
+    on_records = 2000 * (len(window) - off)
+    rate = r["metrics"]["sort_records_per_s"]["value"]
+    assert on_records / (window[-1].end - warm.end) <= rate <= on_records / (window[-1].end - window[0].start)
+
+
+def test_every_job_of_the_window_off_the_path_gives_no_result():
+    """A window in which no job took the cell's path gives no rate, not 0."""
+    import time
+
+    cell = fake_cell()
+    job_fn = answer_job(cell.traffic, backend=[cell.traffic["expect"]["backend"]] + ["host-fallback"] * 99)
+    with pytest.raises(harness.OffPath, match="every job of the window") as e:
+        harness.run(cell, 6, 0.3, False, time.time(), device=CPU, job_fn=job_fn)
+    assert job_fn.calls >= 2
+    assert "backend 'host-fallback'" in str(e.value)
+
+
+def test_a_warm_up_off_the_path_ends_the_run_in_set_up():
+    import time
+
+    cell = fake_cell()
+    job_fn = answer_job(cell.traffic, backend="mesh[4]")
+    with pytest.raises(harness.OffPath, match="warm-up") as e:
+        harness.run(cell, 7, 0.3, False, time.time(), device=CPU, job_fn=job_fn)
+    assert job_fn.calls == 1
+    assert "backend 'mesh[4]', not 'device-parse'" in str(e.value)
+
+
+def test_a_traced_job_off_the_path_gives_no_result():
+    import time
+
+    cell = fake_cell()
+    job_fn = answer_job(cell.traffic, backend=[cell.traffic["expect"]["backend"], "host-fallback"])
+    with pytest.raises(harness.OffPath, match="the traced job"):
+        harness.run(cell, 8, 0.3, True, time.time(), device=CPU, job_fn=job_fn)
+    assert job_fn.calls == 2
+
+
+def test_a_job_that_raises_is_off_the_path():
+    """A job function that raises in every job gives :class:`OffPath` with
+    the error, not the bare exception."""
+    import time
+
+    def crash(src, out, argv):
+        crash.calls += 1
+        raise RuntimeError("the sort crashed")
+
+    crash.calls = 0
+    with pytest.raises(harness.OffPath, match="the sort crashed"):
+        harness.run(fake_cell(), 9, 0.3, False, time.time(), device=CPU, job_fn=crash)
+    assert crash.calls == 1
+
+
+def test_a_run_off_the_path_exits_4_without_a_result(monkeypatch, capsys):
+    from benchmark import run
+
+    def off_path(*args, **kwargs):
+        raise harness.OffPath("wgs_sort.bgzf6", "the warm-up job", ["backend 'mesh[4]', not 'device-parse'"])
+
+    monkeypatch.setattr(run, "use_checkout_cache", lambda: None)
+    monkeypatch.setattr(harness, "run", off_path)
+    assert run.main(["--workload", "wgs_sort.bgzf6", "--seed", "1", "--seconds", "1"]) == 4
+    out, err = capsys.readouterr()
+    assert out == ""
+    assert "cell wgs_sort.bgzf6: the warm-up job did not take the cell's path" in err
+    assert "backend 'mesh[4]', not 'device-parse'" in err
 
 
 def test_failure_rule():

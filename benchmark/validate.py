@@ -40,7 +40,7 @@ def readings(cell, seeds: List[int], job_fn=None, controls: Optional[List[str]] 
             corpus = gen.generate(cell.config, int(tr["records_per_job"]), seed)
             src = os.path.join(work, "input")
             gen.write(corpus, src, int(tr["input_level"]))
-            job = job_fn(src, os.path.join(work, "out"), list(tr["argv"]))
+            job = harness.call_job(job_fn, src, os.path.join(work, "out"), list(tr["argv"]))
             want = ref.expected(corpus, tr)
             data = b""
             if job.error:
@@ -48,7 +48,7 @@ def readings(cell, seeds: List[int], job_fn=None, controls: Optional[List[str]] 
             else:
                 with open(job.out, "rb") as f:
                     data = f.read()
-            rules = harness.failure_reasons(job.report, tr.get("expect", {})) if job.report else ["no report"]
+            rules = harness.path_breaks(job, tr.get("expect", {}))
             yield {"side": "program", "seed": seed, "wall_s": job.wall, **ref.compare(data, want),
                    "path_rule": rules}
             for name in controls:
